@@ -10,9 +10,11 @@ Three measurements:
               service answers its first request at warm-dispatch cost
               because every compile (bitcell characterization,
               calibration, PPA traces, the bucketed fold) already
-              happened before traffic arrived.  A second warmed run
-              reusing a JAX persistent-compilation-cache directory
-              measures how much of the warmup itself survives restarts.
+              happened before traffic arrived.  Two more warmed runs
+              with the persistent compilation cache on (the first fills
+              it, the second reads it) measure how much of the warmup
+              itself survives restarts.  The children need the device,
+              so they run before this process imports jax.
 
   throughput  8 concurrent compatible golden-derived requests (isocap
               scenario slices x capacity variants) through the coalescing
@@ -31,13 +33,8 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 import threading
 import time
-
-from repro.core import sweep
-from repro.core.sweep import SymbolicSweepSpec
-from repro.sweep.service import SweepService
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 JSON_PATH = "benchmarks/BENCH_serve.json"
@@ -48,13 +45,14 @@ REPS = 5
 _CHILD = r"""
 import json, sys, time
 cfg = json.loads(sys.argv[1])
-from repro.sweep.service import SweepService
+from repro.sweep.service import SweepService, enable_compilation_cache
+if cfg["compile_cache"]:
+    enable_compilation_cache()
 svc = SweepService(window_ms=0.0)
 out = {}
 if cfg["warmup"]:
     t0 = time.perf_counter()
-    svc.warmup(specs=[cfg["spec_path"]],
-               compile_cache_dir=cfg.get("cache_dir"))
+    svc.warmup(specs=[cfg["spec_path"]])
     out["warmup_s"] = time.perf_counter() - t0
 with open(cfg["spec_path"]) as f:
     doc = json.load(f)
@@ -67,11 +65,18 @@ print(json.dumps(out))
 """
 
 
-def _child_run(warmup: bool, cache_dir: str | None = None) -> dict:
-    cfg = {"warmup": warmup, "cache_dir": cache_dir,
+def _child_run(warmup: bool, compile_cache: bool = False) -> dict:
+    """One fresh process.  A parent that has imported jax may hold the
+    device the child needs, so that is refused outright."""
+    if "jax" in sys.modules:
+        raise RuntimeError("bench_serve's cold-start children must be "
+                           "started before this process imports jax")
+    cfg = {"warmup": warmup, "compile_cache": compile_cache,
            "spec_path": os.path.join(ROOT, "specs", "isocap.json")}
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    if not compile_cache:   # a cold child must not read a cache from env
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD, json.dumps(cfg)],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
@@ -101,7 +106,7 @@ def _request_docs(copies: int) -> list[dict]:
     return [d for d in docs for _ in range(copies)]
 
 
-def _fire(svc: SweepService, docs: list[dict],
+def _fire(svc, docs: list[dict],
           want=("summary",)) -> tuple[list[dict], float]:
     # threads are spawned outside the timed region and released together:
     # the clock measures burst-to-last-response wall time only
@@ -126,7 +131,7 @@ def _fire(svc: SweepService, docs: list[dict],
     return responses, dt
 
 
-def _serial(svc: SweepService, docs: list[dict]) -> float:
+def _serial(svc, docs: list[dict]) -> float:
     t0 = time.perf_counter()
     for d in docs:
         resp = svc.handle({"spec": d, "want": ["summary"]})
@@ -135,6 +140,9 @@ def _serial(svc: SweepService, docs: list[dict]) -> float:
 
 
 def _parity(responses: list[dict], docs: list[dict]) -> float:
+    from repro.core import sweep
+    from repro.core.sweep import SymbolicSweepSpec
+
     worst = 0.0
     for d, resp in zip(docs, responses):
         want = sweep.run(SymbolicSweepSpec.from_json(d).resolve()).rows()
@@ -157,16 +165,19 @@ def run(quick: bool = False) -> dict:
     reps = 2 if quick else REPS
     copies = 2 if quick else 8
 
-    # cold start vs warmed first request (fresh process each)
+    # cold start vs warmed first request (fresh process each), all before
+    # this process imports jax
     cold = _child_run(warmup=False)
     warmed = _child_run(warmup=True)
-    cache_dir = tempfile.mkdtemp(prefix="deepnvm-jaxcache-")
     warm_hist = {}
     if not quick:
-        _child_run(warmup=True, cache_dir=cache_dir)       # populate
-        reused = _child_run(warmup=True, cache_dir=cache_dir)
+        _child_run(warmup=True, compile_cache=True)        # populate
+        reused = _child_run(warmup=True, compile_cache=True)
         warm_hist = {"warmup_s_fresh": warmed["warmup_s"],
                      "warmup_s_cached": reused["warmup_s"]}
+
+    from repro.core.sweep import SymbolicSweepSpec
+    from repro.sweep.service import SweepService
 
     # concurrent coalesced vs serial throughput on the golden specs.
     # A near-zero window: a simultaneous burst coalesces through queueing

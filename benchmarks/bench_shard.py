@@ -13,13 +13,13 @@ Three measurements:
                 are multi-GB, which is exactly what the sharded path
                 exists to avoid.
 
-  device scan   the same spec with ``ShardPlan(devices=N)`` for N forced
-                host devices.  jax fixes its device count at process
-                startup, so each point runs in a subprocess with
-                ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-                (the worker mode of this module).  Scaling is bounded by
-                physical cores — the recorded numbers are honest for the
-                machine that ran them.
+  device scan   the same spec with ``ShardPlan(devices=N)`` over the
+                first N of this process's devices, for each N in
+                DEVICE_COUNTS that the process has.  Everything runs in
+                this one process, which holds the devices; on the CPU,
+                set ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
+                before starting it to get four host devices.  Host
+                scaling is bounded by physical cores.
 
   parity        sharded-vs-unsharded max relative error on the quick
                 spec (small enough to evaluate unsharded), pinned 1e-12.
@@ -30,8 +30,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -81,32 +79,18 @@ def _parity(quick_spec) -> float:
     return worst
 
 
-def _worker(devices: int, quick: bool) -> None:
-    """Subprocess mode: evaluate the spec on a forced-device-count mesh
-    and print one JSON result line (stdout is the IPC channel)."""
+def _device_point(spec, devices: int) -> dict:
+    """The spec on a mesh of the first ``devices`` devices, timed warm."""
     from repro.core import sweep
-    spec = _spec(quick)
     plan = sweep.ShardPlan(scenario_chunk=8, design_chunk=32,
                            devices=devices, by_width=True)
     _time_plan(spec, plan)  # warm: jit + design-table lowering
-    print(json.dumps(_time_plan(spec, plan)))
-
-
-def _spawn_worker(devices: int, quick: bool) -> dict:
-    env = dict(os.environ)
-    flag = f"--xla_force_host_platform_device_count={devices}"
-    env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} {flag}".strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", env.get("PYTHONPATH")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.bench_shard",
-           "--worker", "--devices", str(devices)] + \
-        (["--quick"] if quick else [])
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         check=True)
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    return _time_plan(spec, plan)
 
 
 def run(quick: bool = False) -> dict:
+    import jax
+
     from repro.core import sweep
     spec = _spec(quick)
     cells = sweep.n_cells(spec)
@@ -119,15 +103,17 @@ def run(quick: bool = False) -> dict:
                                by_width=True)
         chunk_scan.append(_time_plan(spec, plan))
 
-    device_scan = [_spawn_worker(n, quick)
-                   for n in (DEVICE_COUNTS[:1] + DEVICE_COUNTS[-1:]
-                             if quick else DEVICE_COUNTS)]
+    counts = DEVICE_COUNTS[:1] + DEVICE_COUNTS[-1:] if quick \
+        else DEVICE_COUNTS
+    device_scan = [_device_point(spec, n) for n in counts
+                   if n <= len(jax.devices())]
 
     parity = _parity(_spec(quick=True))
 
     best = max(chunk_scan + device_scan, key=lambda r: r["cells_per_s"])
     result = dict(
         shard="chunked + shard_map sweep lowering",
+        device=jax.devices()[0].device_kind,
         spec=spec.name, cells=cells,
         chunk_scan=chunk_scan, device_scan=device_scan,
         parity_max_rel_err=parity,
@@ -155,14 +141,8 @@ def run(quick: bool = False) -> dict:
 def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--worker", action="store_true",
-                    help="internal: single device-count measurement")
-    ap.add_argument("--devices", type=int, default=1)
     args = ap.parse_args(argv)
-    if args.worker:
-        _worker(args.devices, args.quick)
-    else:
-        print(run(quick=args.quick)["derived"])
+    print(run(quick=args.quick)["derived"])
 
 
 if __name__ == "__main__":

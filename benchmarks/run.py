@@ -17,6 +17,10 @@ CI benchmark-smoke job runs ``--only fig3_4_isocap,lm_nvm,fig_dtco,fig_dtco_isoa
 --quick`` so analysis-layer regressions fail fast.  ``--quick`` is forwarded to
 modules whose ``run`` accepts a ``quick`` keyword (reduced reps / arch
 sets); the rest run unchanged.
+
+Each module runs in a process of its own (``--module NAME``), and this
+orchestrator never imports jax: a process that has touched jax holds the
+device, and a module such as ``bench_serve`` starts children that need it.
 """
 
 from __future__ import annotations
@@ -26,10 +30,10 @@ import importlib
 import inspect
 import json
 import os
+import subprocess
+import sys
 import time
 from datetime import datetime, timezone
-
-from repro.core.report import write_csv
 
 HISTORY_PATH = "benchmarks/BENCH_history.jsonl"
 HISTORY_SCHEMA = "deepnvm.bench/1"
@@ -86,30 +90,45 @@ def select(only: list[str] | None) -> tuple[str, ...]:
     return tuple(n for n in MODULES if n in wanted)
 
 
+def run_module(name: str, quick: bool) -> None:
+    """One module in this process: its CSV line, history entry and rows."""
+    mod = importlib.import_module(f"benchmarks.{name}")
+    kwargs = {"quick": True} if quick and \
+        "quick" in inspect.signature(mod.run).parameters else {}
+    t0 = time.perf_counter()
+    result = mod.run(**kwargs)
+    dt_us = (time.perf_counter() - t0) * 1e6
+    # imported after the run: it loads jax, and bench_serve starts its
+    # children only from a process that has not
+    from repro.core.report import write_csv
+
+    derived = result.get("derived", "")
+    print(f'{name},{dt_us:.0f},"{derived}"', flush=True)
+    append_history(name, dt_us, result, quick)
+    if result.get("rows"):
+        write_csv(f"runs/benchmarks/{name}.csv", result["rows"])
+    if result.get("ppa"):
+        write_csv(f"runs/benchmarks/{name}_ppa.csv", result["ppa"])
+
+
 def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--only", action="append", metavar="MODULE",
                     help="run only this module (repeatable, comma-separated)")
     ap.add_argument("--quick", action="store_true",
                     help="reduced work where a module supports it")
+    ap.add_argument("--module", choices=MODULES,
+                    help="run this one module in this process")
     args = ap.parse_args(argv)
-    names = select(args.only)
+    if args.module:
+        run_module(args.module, args.quick)
+        return
 
-    print("name,us_per_call,derived")
-    for name in names:
-        mod = importlib.import_module(f"benchmarks.{name}")
-        kwargs = {"quick": True} if args.quick and \
-            "quick" in inspect.signature(mod.run).parameters else {}
-        t0 = time.perf_counter()
-        result = mod.run(**kwargs)
-        dt_us = (time.perf_counter() - t0) * 1e6
-        derived = result.get("derived", "")
-        print(f'{name},{dt_us:.0f},"{derived}"')
-        append_history(name, dt_us, result, args.quick)
-        if result.get("rows"):
-            write_csv(f"runs/benchmarks/{name}.csv", result["rows"])
-        if result.get("ppa"):
-            write_csv(f"runs/benchmarks/{name}_ppa.csv", result["ppa"])
+    print("name,us_per_call,derived", flush=True)
+    for name in select(args.only):
+        subprocess.run([sys.executable, "-m", "benchmarks.run",
+                        "--module", name] + (["--quick"] if args.quick
+                                             else []), check=True)
 
 
 if __name__ == "__main__":

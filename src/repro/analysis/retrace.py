@@ -1,8 +1,8 @@
 """DNVM002 — jax.jit retrace/trace-time discipline.
 
 The engines trace their kernels a fixed number of times (PR 7 pins
-``node_retraces == 0``) and run everything float64 under
-``jax.experimental.enable_x64``.  Three trace-time hazards break those
+``node_retraces == 0``) and run everything float64 inside the
+``jax.enable_x64(True)`` context.  Three trace-time hazards break those
 contracts silently:
 
 - **varying-global capture**: a jitted body reads a module-level name

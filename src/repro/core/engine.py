@@ -22,7 +22,7 @@ One jitted function maps the cross product
     [node] x [tech] x [cap] x [org]  ->  PPA tensors of shape [n, m, c, o]
 
 re-expressing every latency/energy/leakage/area equation of cachemodel.py
-as a pure array function.  Float64 throughout (jax.experimental.enable_x64)
+as a pure array function.  Float64 throughout (the jax.enable_x64 context)
 so the batched numbers agree with the scalar Python-float path to the last
 few ulps, keeping the Table I/II calibration anchors intact.  A cross-node
 DTCO sweep (Mishty & Sadi 2023 run their SOT-MRAM study per node by hand)
@@ -48,7 +48,6 @@ import itertools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import bitcell as bitcell_mod
 from repro.core.cachemodel import (
@@ -497,7 +496,7 @@ def _run_kernel(cell_mat, cal_mat, is_sram, node_mat, caps_arr,
     anchor_row = np.array([np.array_equal(p, _PERI_16NM_ROW) for p in peri])
 
     def run(sel, anchor_peri):
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _ppa_kernel(cell_mat[sel], cal_mat[sel], is_sram,
                               node4[sel], peri[sel], caps_arr,
                               banks, rows, cols, acc,

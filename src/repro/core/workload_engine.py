@@ -52,7 +52,6 @@ from collections.abc import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import traffic
 from repro.core.cachemodel import LINE_BYTES, CacheDesign
@@ -394,7 +393,7 @@ def _evaluate_cached(stats_seq: tuple[TrafficStats, ...],
     batch = pack(stats_seq)
     rl, wl, re_, we_, leak, caps = _design_vectors(designs)
     pmat = np.stack([_platform_vector(p) for p in platforms])
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _fold_kernel(batch.bytes_total, batch.is_write,
                            batch.reuse_distance, batch.dram_visible,
                            batch.mask, batch.macs,
@@ -444,7 +443,7 @@ def evaluate_chunk(stats_seq: Sequence[TrafficStats],
     batch = pack(stats_seq, width=width)
     rl, wl, re_, we_, leak, caps = _design_vectors(designs)
     pmat = np.stack([_platform_vector(p) for p in platforms])
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _fold_kernel(batch.bytes_total, batch.is_write,
                            batch.reuse_distance, batch.dram_visible,
                            batch.mask, batch.macs,
@@ -458,7 +457,6 @@ def _sharded_fold(mesh):
     leading chunk axis split across devices (the platform matrix is
     replicated), and each device evaluates its chunk independently — the
     fold has no cross-chunk terms, so no collectives are needed."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import SWEEP_AXIS
@@ -471,9 +469,9 @@ def _sharded_fold(mesh):
                     rl[0], wl[0], re_[0], we_[0], leak[0], caps[0], pmat)
         return {k: v[None] for k, v in out.items()}
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(sh,) * 12 + (P(),),
-                             out_specs=sh))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(sh,) * 12 + (P(),),
+                                 out_specs=sh))
 
 
 def evaluate_chunk_group(chunk_stats: Sequence[Sequence[TrafficStats]],
@@ -504,7 +502,7 @@ def evaluate_chunk_group(chunk_stats: Sequence[Sequence[TrafficStats]],
     vecs = [np.stack(v) for v in
             zip(*(_design_vectors(tuple(cd)) for cd in chunk_designs))]
     pmat = np.stack([_platform_vector(p) for p in platforms])
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _sharded_fold(mesh)(*stacked, *vecs, pmat)
     out = {k: np.asarray(v) for k, v in out.items()}
     return [_tables_from({k: v[i] for k, v in out.items()},
@@ -564,7 +562,7 @@ def evaluate_bucketed(stats_seq: Sequence[TrafficStats],
     macs = _pad_axis(batch.macs, sp, 0.0)
     vecs = [np.pad(v, (0, dp - d)) for v in _design_vectors(designs)]
     pmat = np.stack([_platform_vector(p) for p in platforms])
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _fold_kernel(bt, iw, rd, vis, mask, macs, *vecs, pmat)
     sliced = {}
     for k, v in out.items():
@@ -597,7 +595,7 @@ def warmup_fold(shape: tuple[int, int, int, int]) -> None:
     false_sk = np.zeros((s, k), dtype=bool)
     vec = np.zeros(d)
     pmat = np.ones((p, len(PLATFORM_FIELDS)))  # ones: no 0-divides
-    with enable_x64():
+    with jax.enable_x64(True):
         _fold_kernel(zeros_sk, false_sk, np.full((s, k), np.inf), false_sk,
                      false_sk, np.zeros(s), vec, vec, vec, vec, vec,
                      np.ones(d), pmat)
@@ -626,7 +624,7 @@ def dram_tx(stats_seq: Sequence[TrafficStats],
     ``TrafficStats.dram_tx`` (paper Fig. 6's capacity sweep)."""
     batch = pack(stats_seq)
     caps = np.array([float(c) for c in capacities_bytes], dtype=np.float64)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _miss_tx_kernel(batch.bytes_total, batch.reuse_distance,
                               batch.dram_visible & batch.mask, caps)
     return np.asarray(out)

@@ -27,7 +27,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import bitcell as bitcell_mod
 from repro.core import calibration, engine, mtj, workload_engine
@@ -100,7 +99,7 @@ def grid_argmin(problem: InverseProblem, lowered: Lowered | None = None,
     """The Algorithm-1-style reference: argmin of the problem objective
     over the grid corners x orgs through the standard memoized engine
     path, restricted to the area budget."""
-    with enable_x64():
+    with jax.enable_x64(True):
         lowered = lowered if lowered is not None else relax.lower(problem)
         obj, area = lowered.grid_objective()
         ki, oi = lowered.masked_argmin(obj, area)
@@ -116,7 +115,7 @@ def recover_corner(problem: InverseProblem, lowered: Lowered | None = None,
     pinned and the softmins at :data:`HARD_TEMP`, the selected (corner,
     org) must recover :func:`grid_argmin`'s winner — the softmin ->
     argmin consistency check."""
-    with enable_x64():
+    with jax.enable_x64(True):
         lowered = lowered if lowered is not None else relax.lower(problem)
         obj, area, _ = lowered.objective_matrix(lowered.theta0, HARD_TEMP)
         obj, area = np.asarray(obj), np.asarray(area)
@@ -153,7 +152,7 @@ def verify(lowered: Lowered, theta: np.ndarray, ki: int, oi: int) -> dict:
     """Re-evaluate one converged (theta, corner, org) point through the
     standard (non-relaxed) pipeline and report the objective value, the
     materialized :class:`CacheDesign`, and the per-field PPA tensors."""
-    with enable_x64():
+    with jax.enable_x64(True):
         p = lowered.points[ki]
         cell = _standard_cell(lowered, theta, ki)
         cal = calibration.get(p.mem, p.node)
@@ -186,7 +185,7 @@ def verify(lowered: Lowered, theta: np.ndarray, ki: int, oi: int) -> dict:
 def solve(problem: InverseProblem) -> InverseResult:
     """Full inverse solve: lower, multi-start descent, harden, pick the
     best area-feasible start, verify through the standard path."""
-    with enable_x64():
+    with jax.enable_x64(True):
         lowered = relax.lower(problem)
         theta0s = _theta_starts(lowered)
         thetas, losses = _solve_starts(lowered, theta0s)

@@ -41,7 +41,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import bitcell as bitcell_mod
 from repro.core import calibration, engine, workload_engine
@@ -70,11 +69,6 @@ LAMBDA_WALL = 10.0
 # within ~1% of the budget.
 SIGMA_AREA = 0.01
 LAMBDA_AREA = 50.0
-
-# Overdrive clamp for masked (infeasible) assignments: keeps the masked
-# branch finite (inf * 0 would poison the softmin mixture's gradients)
-# without perturbing any feasible overdrive the sweep would accept.
-_OD_FLOOR = 1e-30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,8 +122,12 @@ def soft_cell(theta_g, group: LeafGroup, temp):
         od_set = a.i_write_a / ic0_set_a - 1.0
         od_reset = a.i_write_a / ic0_reset_a - 1.0
         od_min = jnp.minimum(od_set, od_reset)
-        t_set_s = tau_set_s / jnp.maximum(od_set, _OD_FLOOR)
-        t_reset_s = tau_reset_s / jnp.maximum(od_reset, _OD_FLOOR)
+        # a non-positive overdrive only feeds a masked (weight-0) branch:
+        # divide it by 1.0 so that branch and its 1/od^2 gradient stay
+        # within float32's exponent range, which bounds the TPU's
+        # emulated float64 (0 * inf would poison the mixture's gradient)
+        t_set_s = tau_set_s / jnp.where(od_set > 0.0, od_set, 1.0)
+        t_reset_s = tau_reset_s / jnp.where(od_reset > 0.0, od_reset, 1.0)
         if group.flavor == "stt":
             i_read_a = jnp.minimum(a.i_read_raw_a,
                                    _STT_READ_CAP_FRAC * ic0_set_a)
@@ -428,7 +426,7 @@ def lower(problem: InverseProblem) -> Lowered:
 
     budget = problem.area_budget_mm2
     if budget == "iso":
-        with enable_x64():
+        with jax.enable_x64(True):
             _, grid_areas = lowered.grid_objective()
         budget = _iso_budget(grid_areas)
     if budget is not None:

@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import scenarios as scenarios_mod
 from repro.inverse import relax
@@ -47,7 +46,7 @@ def sensitivity_rows(problem: InverseProblem,
     d ln(objective) / d ln(leaf).  For the "edap" objective the
     platform/scenario columns are None (EDAP has no workload axis).
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         lowered = lowered if lowered is not None else relax.lower(problem)
         theta = lowered.theta0 if theta is None else np.asarray(theta)
         org_idx = winner_orgs(lowered)

@@ -36,8 +36,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 
     def body(i, carry):
         m, l, acc = carry
-        ks = pl.load(k_ref, (pl.dslice(i * block_k, block_k), slice(None)))
-        vs = pl.load(v_ref, (pl.dslice(i * block_k, block_k), slice(None)))
+        ks = k_ref[pl.dslice(i * block_k, block_k), :]
+        vs = v_ref[pl.dslice(i * block_k, block_k), :]
         s = jax.lax.dot_general(q, ks.astype(jnp.float32),
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)

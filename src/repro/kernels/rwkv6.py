@@ -26,17 +26,25 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scratch,
                 *, chunk: int, seq: int):
     hd = r_ref.shape[-1]
     s_scratch[...] = jnp.zeros((hd, hd), jnp.float32)
-    u = u_ref[...].astype(jnp.float32)                     # (hd,)
+    u = u_ref[...].astype(jnp.float32)                     # (1,hd)
     n_chunks = seq // chunk
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (the TPU lowering has no cumsum)
+    tril = jnp.where(t_idx >= i_idx, 1.0, 0.0).astype(jnp.float32)
 
     def body(ci, _):
         sl = (pl.dslice(ci * chunk, chunk), slice(None))
-        r = pl.load(r_ref, sl).astype(jnp.float32)         # (C,hd)
-        k = pl.load(k_ref, sl).astype(jnp.float32)
-        v = pl.load(v_ref, sl).astype(jnp.float32)
-        w = pl.load(w_ref, sl).astype(jnp.float32)
+        r = r_ref[sl].astype(jnp.float32)                  # (C,hd)
+        k = k_ref[sl].astype(jnp.float32)
+        v = v_ref[sl].astype(jnp.float32)
+        w = w_ref[sl].astype(jnp.float32)
         logw = jnp.log(jnp.maximum(w, 1e-30))
-        cum = jnp.cumsum(logw, axis=0)                     # (C,hd) inclusive
+        cum = jax.lax.dot_general(                         # (C,hd) inclusive
+            tril, logw, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
         cum_ex = cum - logw                                # exclusive: j < t
         # state contribution: r_t * prod_{j<t} w_j applied to S_0
         r_dec = r * jnp.exp(cum_ex)
@@ -48,24 +56,28 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scratch,
         # neither factor over/underflows (valid while the per-chunk decay
         # range stays within fp32 exponent headroom — chunk=128 with
         # realistic RWKV decays; see module docstring)
-        c_mid = cum[chunk // 2, :][None, :]
+        # rows are taken as static (1,hd) slices: the TPU lowering has no
+        # dynamic_slice, which integer indexing of a value would emit
+        c_mid = jax.lax.slice_in_dim(cum, chunk // 2, chunk // 2 + 1)
         r_sc = r * jnp.exp(cum_ex - c_mid)                 # (C,hd)
         k_sc = k * jnp.exp(c_mid - cum)                    # (C,hd)
         att = jax.lax.dot_general(r_sc, k_sc, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
         att = jnp.where(t_idx > i_idx, att, 0.0)           # strict past
         y_intra = jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
         # current-token bonus: r_t (u * k_t) v_t
-        bonus = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True) * v
+        bonus = jnp.sum(r * u * k, axis=1, keepdims=True) * v
         y = y_state + y_intra + bonus
-        pl.store(y_ref, sl, y.astype(y_ref.dtype))
+        y_ref[sl] = y.astype(y_ref.dtype)
         # carry state: S <- diag(prod w) S_0 + sum_i (prod_{j>i} w) k_i v_i
-        decay_all = jnp.exp(cum[-1, :])                    # (hd,)
-        k_tail = k * jnp.exp(cum[-1:, :] - cum)            # (C,hd)
-        s_new = decay_all[:, None] * s0 + jax.lax.dot_general(
+        cum_last = jax.lax.slice_in_dim(cum, chunk - 1, chunk)   # (1,hd)
+        log_decay = jax.lax.dot_general(                   # (hd,1) column
+            logw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        k_tail = k * jnp.exp(cum_last - cum)               # (C,hd)
+        s_new = jnp.exp(log_decay) * s0 + jax.lax.dot_general(
             k_tail, v, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         s_scratch[...] = s_new
@@ -82,7 +94,9 @@ def wkv6(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
     fold = lambda t: jnp.moveaxis(t, 2, 1).reshape(b * h, s, hd)  # noqa: E731
     rr, kk, vv, ww = fold(r), fold(k), fold(v), fold(w)
     uu = u.reshape(h, hd)
-    uu = jnp.broadcast_to(uu[None], (b, h, hd)).reshape(b * h, hd)
+    # one (1, hd) row per grid step: a block's last two dims must equal the
+    # array's (or tile by 8 x 128) for the TPU lowering
+    uu = jnp.broadcast_to(uu[None], (b, h, hd)).reshape(b * h, 1, hd)
 
     kernel = functools.partial(_wkv_kernel, chunk=chunk, seq=s)
     y = pl.pallas_call(
@@ -93,7 +107,7 @@ def wkv6(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
             pl.BlockSpec((None, s, hd), lambda i: (i, 0, 0)),
             pl.BlockSpec((None, s, hd), lambda i: (i, 0, 0)),
             pl.BlockSpec((None, s, hd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((None, hd), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, hd), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, s, hd), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, hd), jnp.float32),

@@ -3,4 +3,4 @@
 from repro.sweep_cli import main
 
 if __name__ == "__main__":
-    main()
+    main(compile_cache=True)

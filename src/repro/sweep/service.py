@@ -63,6 +63,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import pathlib
 import socketserver
 import sys
 import threading
@@ -592,8 +593,7 @@ class SweepService:
 
     # -- warmup ------------------------------------------------------------
 
-    def warmup(self, specs: Sequence = (), compile_cache_dir=None,
-               grid: bool = False) -> dict:
+    def warmup(self, specs: Sequence = (), grid: bool = False) -> dict:
         """Kill the cold start before the first request lands.
 
         ``specs`` (paths, documents, symbolic or concrete specs) warm the
@@ -602,15 +602,11 @@ class SweepService:
         tunings), and the fold kernel at each spec's bucketed (s, k, d, p)
         shape.  ``grid`` additionally pre-traces the spec-independent
         shape grids (``engine.warmup`` + ``workload_engine.warmup``).
-        ``compile_cache_dir`` wires the JAX persistent compilation cache
-        first, so the traces this warmup compiles are reused across
-        process restarts."""
+        Where the entry point has turned on the persistent compilation
+        cache (:func:`enable_compilation_cache`), these compiles are
+        reused across process restarts."""
         t0 = time.perf_counter()
         info: dict = {"specs": [], "grid": bool(grid), "fold_shapes": 0}
-        if compile_cache_dir:
-            info["compile_cache"] = enable_compilation_cache(
-                compile_cache_dir)
-            info["compile_cache_dir"] = str(compile_cache_dir)
         if grid:
             info["engine_tables"] = engine.warmup()
             info["fold_shapes"] += workload_engine.warmup()
@@ -695,22 +691,39 @@ def _as_spec(item) -> SweepSpec:
     raise TypeError(f"cannot warm up from {type(item).__name__}")
 
 
-def enable_compilation_cache(path) -> bool:
-    """Wire the JAX persistent compilation cache at ``path`` (created if
-    missing, thresholds dropped so every fold/PPA trace is persisted).
-    Compiled executables then survive process restarts: a service booting
-    with the same cache dir skips straight past the XLA compiles that
-    dominate the cold start.  Returns False if this jax build lacks the
-    knobs (the service still runs, just without cross-process reuse)."""
+# Where the persistent compilation cache lives when JAX_COMPILATION_CACHE_DIR
+# is unset: one fixed directory at the checkout root.  The directory is part
+# of what the cache is found by, so a path that moves between runs never hits.
+DEFAULT_COMPILE_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def enable_compilation_cache(path: str | None = None) -> str:
+    """Turn on the JAX persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, names the directory, and no
+    other is chosen here: a ``path`` naming another one raises
+    ``ValueError``.  Otherwise the cache goes to ``path`` or, without one,
+    to :data:`DEFAULT_COMPILE_CACHE_DIR`.  Thresholds are dropped so every
+    PPA/fold compile is persisted: a process started later with the same
+    directory skips the XLA compiles that dominate the cold start.  Any
+    failure to create the directory or set the knobs raises."""
     import jax
-    try:
-        os.makedirs(str(path), exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # pragma: no cover — version-dependent knobs
-        return False
-    return True
+    from jax.experimental.compilation_cache import compilation_cache
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env and path and os.path.abspath(path) != os.path.abspath(env):
+        raise ValueError(f"compile cache {path!r} contradicts "
+                         f"JAX_COMPILATION_CACHE_DIR={env!r}")
+    path = env or path or DEFAULT_COMPILE_CACHE_DIR
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # the cache binds its directory at the first compile; rebind it
+    compilation_cache.reset_cache()
+    return path
 
 
 # ---------------------------------------------------------------------------

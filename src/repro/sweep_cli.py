@@ -35,8 +35,8 @@ transport flag it keeps the historical stdin JSONL contract — one request
 per line in, one response line out; ``--http HOST:PORT`` and/or
 ``--unix PATH`` start threaded socket transports over the same handler
 (``--stdin`` adds the stdin loop alongside them).  ``--warmup`` /
-``--warmup-spec PATH`` / ``--compile-cache DIR`` pre-trace kernels before
-the first request; ``--window-ms`` / ``--max-batch`` / ``--no-coalesce``
+``--warmup-spec PATH`` pre-trace kernels before the first request;
+``--window-ms`` / ``--max-batch`` / ``--no-coalesce``
 tune the coalescing window; ``--stats-on-exit`` prints the stats document
 to stderr on shutdown.  SIGTERM/SIGINT shut down gracefully: in-flight
 requests (including any in the coalescing window) are answered first.
@@ -54,6 +54,12 @@ The response is one JSON object: ``{"ok": true, "name": ..., "axes":
 "coalesced" | "cache" | "sharded", <one key per requested view>}`` — or
 ``{"ok": false, "error": ...}`` on a bad request (the process keeps
 serving).
+
+Run from the command line, every subcommand keeps JAX's persistent
+compilation cache in ``$JAX_COMPILATION_CACHE_DIR`` or, where that is
+unset, in ``serve --compile-cache DIR`` or ``.jax_cache/`` at the checkout
+root (:func:`repro.sweep.service.enable_compilation_cache`); a
+``--compile-cache`` that contradicts the environment is an error.
 """
 
 from __future__ import annotations
@@ -282,9 +288,8 @@ def cmd_serve(args: argparse.Namespace) -> None:
                       coalesce=not args.no_coalesce,
                       max_pending=args.max_pending,
                       max_body_bytes=args.max_body_bytes)
-    if args.warmup or args.warmup_spec or args.compile_cache:
+    if args.warmup or args.warmup_spec:
         info = svc.warmup(specs=tuple(args.warmup_spec or ()),
-                          compile_cache_dir=args.compile_cache,
                           grid=args.warmup)
         print(f"warmup: {info['fold_shapes']} fold shapes, "
               f"{info.get('engine_tables', 0)} engine tables, "
@@ -332,7 +337,11 @@ def cmd_serve(args: argparse.Namespace) -> None:
             print(json.dumps(svc.stats(), indent=2), file=sys.stderr)
 
 
-def main(argv: list[str] | None = None) -> None:
+def main(argv: list[str] | None = None, *,
+         compile_cache: bool = False) -> None:
+    """``compile_cache`` turns the persistent compilation cache on, as the
+    command line does; in-process callers leave it off unless they pass
+    ``serve --compile-cache``."""
     ap = argparse.ArgumentParser(
         prog="python -m repro.sweep",
         description=__doc__.splitlines()[0])
@@ -437,16 +446,23 @@ def main(argv: list[str] | None = None) -> None:
                          help="pre-trace the exact shapes this spec needs "
                               "(repeatable)")
     serve_p.add_argument("--compile-cache", metavar="DIR",
-                         help="enable the JAX persistent compilation "
-                              "cache at DIR (survives restarts)")
+                         help="keep the JAX persistent compilation cache "
+                              "in DIR (an error if JAX_COMPILATION_CACHE_DIR "
+                              "names another directory)")
     serve_p.add_argument("--stats-on-exit", action="store_true",
                          help="print the stats document to stderr on "
                               "shutdown")
     serve_p.set_defaults(func=cmd_serve)
 
     args = ap.parse_args(argv)
+    cache_dir = getattr(args, "compile_cache", None)
+    if compile_cache or cache_dir:
+        try:
+            service_mod.enable_compilation_cache(cache_dir)
+        except ValueError as e:
+            ap.error(str(e))
     args.func(args)
 
 
 if __name__ == "__main__":
-    main()
+    main(compile_cache=True)

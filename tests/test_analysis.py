@@ -196,23 +196,28 @@ class TestRetrace:
 
     def test_dtype_narrowing_fires_only_under_x64(self, tmp_path):
         src = """
+            import contextlib
+
             import jax
             import jax.numpy as jnp
-            {x64}
 
             @jax.jit
             def kernel(x):
                 return x.astype(jnp.float32)
+
+            def run(x):
+                with {ctx}:
+                    return kernel(x)
             """
         fires = run_source(
-            tmp_path, src.format(x64="from jax.experimental import "
-                                     "enable_x64"),
+            tmp_path, src.format(ctx="jax.enable_x64(True)"),
             rules=["DNVM002"])
         assert len(fires.active) == 1
         assert "narrows the enable_x64 float64 contract" in \
             fires.active[0].message
 
-        silent = run_source(tmp_path, src.format(x64=""),
+        silent = run_source(tmp_path,
+                            src.format(ctx="contextlib.nullcontext()"),
                             rules=["DNVM002"], name="no_x64.py")
         assert silent.active == []
 

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro import inverse
 from repro.core import bitcell, tech
@@ -55,7 +54,7 @@ TWO_NODE_DOC = {
 def two_node_lowered():
     prob = inverse.InverseProblem(
         sweep=SymbolicSweepSpec.from_json(TWO_NODE_DOC), objective="edp")
-    with enable_x64():
+    with jax.enable_x64(True):
         yield relax.lower(prob)
 
 
@@ -82,7 +81,7 @@ def test_gradient_matches_finite_differences_on_every_leaf(
     # point breaks the tie by far more than the FD step
     rng = np.random.default_rng(7)
     theta = low.theta0 + rng.uniform(-0.02, 0.02, low.theta0.size)
-    with enable_x64():
+    with jax.enable_x64(True):
         temp = 0.5
         loss = jax.jit(low.loss)
         grad = np.asarray(jax.jit(jax.grad(low.loss))(theta, temp))
@@ -102,7 +101,7 @@ def test_gradient_is_nonzero_on_every_leaf(two_node_lowered):
     # every exposed leaf must actually steer the loss (dead axes would
     # mean a leaf that never reaches a PPA expression)
     low = two_node_lowered
-    with enable_x64():
+    with jax.enable_x64(True):
         grad = np.asarray(jax.grad(low.loss)(low.theta0, 0.5))
     assert np.count_nonzero(grad) == grad.size
 
@@ -121,13 +120,70 @@ def test_hard_soft_cell_matches_characterize(flavor, node):
     # of the theta packing: a few ulps per component, nothing more
     groups = bounds_mod.leaf_groups([(flavor, 3 << 20, node)])
     theta = bounds_mod.pack_theta(groups)
-    with enable_x64():
+    with jax.enable_x64(True):
         cell, od_best = relax.soft_cell(jnp.asarray(theta), groups[0],
                                         relax.HARD_TEMP)
         cell = np.asarray(cell)
     want = bitcell.characterize(flavor, node).as_array()
     assert float(od_best) > 0.0
     np.testing.assert_allclose(cell, want, rtol=1e-13)
+
+
+def _largest_float(closed, *args) -> float:
+    """Evaluate a closed jaxpr eqn by eqn (into nested jit calls) and
+    return the largest finite magnitude any float intermediate takes."""
+    from jax.extend import core as jcore
+
+    largest = 0.0
+
+    def run(jaxpr, consts, *vals):
+        nonlocal largest
+        env = dict(zip(jaxpr.constvars, consts))
+        env.update(zip(jaxpr.invars, vals))
+
+        def read(v):
+            return v.val if isinstance(v, jcore.Literal) else env[v]
+        for eqn in jaxpr.eqns:
+            ins = [read(v) for v in eqn.invars]
+            sub = eqn.params.get("jaxpr")
+            if eqn.primitive.name in ("jit", "pjit") and sub is not None:
+                outs = run(sub.jaxpr, sub.consts, *ins)
+            else:
+                outs = eqn.primitive.bind(*ins, **eqn.params)
+                if not eqn.primitive.multiple_results:
+                    outs = [outs]
+            for o in outs:
+                a = np.asarray(o)
+                if a.dtype.kind == "f" and a.size:
+                    fin = np.abs(a[np.isfinite(a)])
+                    largest = max(largest, float(fin.max(initial=0.0)))
+            env.update(zip(eqn.outvars, outs))
+        return [read(v) for v in jaxpr.outvars]
+
+    run(closed.jaxpr, closed.consts, *args)
+    return largest
+
+
+@pytest.mark.parametrize("flavor", ["stt", "sot"])
+@pytest.mark.parametrize("node", [tech.TECH_16NM,
+                                  tech.scaled_node(7e-9)])
+def test_soft_cell_gradient_stays_in_float32_exponent_range(flavor, node):
+    # the TPU emulates float64 in float32's exponent range: a masked
+    # (write-infeasible) fin assignment must not overflow it in the
+    # forward or the backward pass, or 0 * inf turns the gradient to NaN
+    groups = bounds_mod.leaf_groups([(flavor, 3 << 20, node)])
+    theta = jnp.asarray(bounds_mod.pack_theta(groups))
+
+    def f(th):
+        cell, od_best = relax.soft_cell(th, groups[0], 0.5)
+        return jnp.log(cell).sum() + od_best
+
+    with jax.enable_x64(True):
+        closed = jax.make_jaxpr(jax.value_and_grad(f))(theta)
+        largest = _largest_float(closed, theta)
+        _, grad = jax.value_and_grad(f)(theta)
+    assert np.isfinite(np.asarray(grad)).all()
+    assert largest < float(np.finfo(np.float32).max)
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +196,7 @@ def test_center_recovery_matches_grid_argmin(spec_name):
     prob = inverse.InverseProblem(
         sweep=SymbolicSweepSpec.load(os.path.join(SPECS, spec_name)),
         objective="edp", name=spec_name)
-    with enable_x64():
+    with jax.enable_x64(True):
         low = relax.lower(prob)
         grid = inverse.grid_argmin(prob, low)
         rec = inverse.recover_corner(prob, low)
@@ -157,7 +213,7 @@ def test_scaling_wall_penalty_regression_at_2nm():
     n2 = tech.scaled_node(2e-9, allow_extrapolation=True)
     g2 = bounds_mod.leaf_groups([("stt", 3 << 20, n2)])[0]
     g16 = bounds_mod.leaf_groups([("stt", 3 << 20, tech.TECH_16NM)])[0]
-    with enable_x64():
+    with jax.enable_x64(True):
         _, od2 = relax.soft_cell(
             jnp.asarray(bounds_mod.pack_theta((g2,))), g2, 0.5)
         _, od16 = relax.soft_cell(
@@ -219,7 +275,7 @@ def test_target_mode_drives_objective_to_target(two_node_lowered):
     # target-hitting: ask for an EDP 10% above the center value and check
     # the loss is the squared log residual (zero iff on target)
     low = two_node_lowered
-    with enable_x64():
+    with jax.enable_x64(True):
         import dataclasses
         obj, area, _ = low.objective_matrix(low.theta0)
         ki, oi = low.masked_argmin(np.asarray(obj), np.asarray(area))
@@ -273,7 +329,7 @@ def test_shipped_inverse_spec_loads_and_lowers():
         os.path.join(SPECS, "inverse_isocap.json"))
     assert prob.objective == "edp"
     assert prob.area_budget_mm2 == "iso"
-    with enable_x64():
+    with jax.enable_x64(True):
         low = relax.lower(prob)
     assert low.area_budget_mm2 > 0.0
     assert {g.key[0] for g in low.groups} == {"stt", "sot"}

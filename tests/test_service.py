@@ -36,7 +36,7 @@ import time
 
 import pytest
 
-from repro import scenarios
+from repro import scenarios, sweep_cli
 from repro.core import sweep
 from repro.core.sweep import SymbolicSweepSpec, spec_union
 from repro.sweep import client
@@ -342,9 +342,10 @@ def test_unix_transport_roundtrip(tmp_path):
         svc.close()
 
 
-def test_serve_subprocess_sigterm_graceful():
+def test_serve_subprocess_sigterm_graceful(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax-cache")
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.sweep", "serve",
          "--http", "127.0.0.1:0", "--stats-on-exit"],
@@ -369,10 +370,71 @@ def test_serve_subprocess_sigterm_graceful():
         assert proc.returncode == 0
         stats = json.loads(err)
         assert stats["requests"]["ok"] >= 1
+        # the CLI keeps its compiles where the environment says
+        assert os.listdir(tmp_path / "jax-cache")
     finally:
         if proc.poll() is None:
             proc.kill()
             proc.communicate()
+
+
+@pytest.fixture
+def restore_compile_cache_config():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("env,flag", [
+    pytest.param("env-cache", None, id="env"),
+    pytest.param(None, None, id="default"),
+    pytest.param(None, "flag-cache", id="flag"),
+    pytest.param("env-cache", "env-cache", id="flag-agrees-with-env"),
+])
+def test_compilation_cache_placement(monkeypatch, tmp_path, env, flag,
+                                     restore_compile_cache_config):
+    """JAX_COMPILATION_CACHE_DIR, where set, is the cache; otherwise the
+    directory asked for, else the fixed .jax_cache/ at the checkout root."""
+    import jax
+    import jax.numpy as jnp
+
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = str(tmp_path / (env or flag)) if env or flag \
+        else os.path.join(ROOT, ".jax_cache")
+    got = service_mod.enable_compilation_cache(
+        str(tmp_path / flag) if flag else None)
+    assert got == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)
+    if env or flag:
+        fn = jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
+        fn(jnp.ones(7)).block_until_ready()
+        assert os.listdir(want)
+
+
+def test_compile_cache_flag_contradicting_env_is_an_error(
+        monkeypatch, tmp_path, capsys, restore_compile_cache_config):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    with pytest.raises(ValueError, match="contradicts"):
+        service_mod.enable_compilation_cache(str(tmp_path / "other"))
+    with pytest.raises(SystemExit) as exc:
+        sweep_cli.main(["serve", "--compile-cache", str(tmp_path / "other")])
+    assert exc.value.code == 2
+    assert "contradicts" in capsys.readouterr().err
+    assert not (tmp_path / "other").exists()
 
 
 # ---------------------------------------------------------------------------
