@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import bitcell as bitcell_mod
 from repro.core.cachemodel import (
     ACCESS_TYPES,
@@ -364,27 +365,28 @@ class DesignTable:
         memo = self._tuned_memo
         if (n, mem, capacity_bytes) in memo:
             return memo[n, mem, capacity_bytes]
-        if not self.valid[c].any():
-            raise ValueError(
-                f"empty design space at {capacity_bytes} bytes")
-        rl = self.read_latency_s[n, m, c]
-        wl = self.write_latency_s[n, m, c]
-        re_ = self.read_energy_j[n, m, c]
-        we_ = self.write_energy_j[n, m, c]
-        flat = np.full(N_ORGS, self.area_mm2[n, m, c])
-        leak = np.full(N_ORGS, self.leakage_w[n, m, c])
-        metrics = (rl, wl, re_, we_, rl * re_, wl * we_, flat, leak)
-        edap = self.edap(mem, capacity_bytes, node)
-        best = -1
-        for metric in metrics:
-            for a in range(len(ACCESS_TYPES)):
-                pool = self.valid[c] & (ORG_ACCESS == a)
-                if not pool.any():
-                    continue
-                nominee = int(np.argmin(np.where(pool, metric, np.inf)))
-                if best < 0 or edap[nominee] < edap[best]:
-                    best = nominee
-        memo[n, mem, capacity_bytes] = best
+        with tracing.span("tune"):
+            if not self.valid[c].any():
+                raise ValueError(
+                    f"empty design space at {capacity_bytes} bytes")
+            rl = self.read_latency_s[n, m, c]
+            wl = self.write_latency_s[n, m, c]
+            re_ = self.read_energy_j[n, m, c]
+            we_ = self.write_energy_j[n, m, c]
+            flat = np.full(N_ORGS, self.area_mm2[n, m, c])
+            leak = np.full(N_ORGS, self.leakage_w[n, m, c])
+            metrics = (rl, wl, re_, we_, rl * re_, wl * we_, flat, leak)
+            edap = self.edap(mem, capacity_bytes, node)
+            best = -1
+            for metric in metrics:
+                for a in range(len(ACCESS_TYPES)):
+                    pool = self.valid[c] & (ORG_ACCESS == a)
+                    if not pool.any():
+                        continue
+                    nominee = int(np.argmin(np.where(pool, metric, np.inf)))
+                    if best < 0 or edap[nominee] < edap[best]:
+                        best = nominee
+            memo[n, mem, capacity_bytes] = best
         return best
 
     def tuned(self, mem: str, capacity_bytes: int,
@@ -496,12 +498,13 @@ def _run_kernel(cell_mat, cal_mat, is_sram, node_mat, caps_arr,
     anchor_row = np.array([np.array_equal(p, _PERI_16NM_ROW) for p in peri])
 
     def run(sel, anchor_peri):
-        with jax.enable_x64(True):
+        with tracing.span("ppa.dispatch"), jax.enable_x64(True):
             out = _ppa_kernel(cell_mat[sel], cal_mat[sel], is_sram,
                               node4[sel], peri[sel], caps_arr,
                               banks, rows, cols, acc,
                               anchor_peri=anchor_peri)
-        return {k: np.asarray(v) for k, v in out.items()}
+        with tracing.span("ppa.fetch"):
+            return {k: np.asarray(v) for k, v in out.items()}
 
     if anchor_row.all():
         return run(slice(None), True)

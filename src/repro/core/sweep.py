@@ -61,6 +61,7 @@ from collections.abc import Iterable, Mapping, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core import dse, engine, report, tech, workload_engine
 from repro.core.cachemodel import CacheDesign
 from repro.core.tech import Platform, GTX_1080TI, TechNode, TECH_16NM
@@ -511,17 +512,18 @@ def lower_designs(points: Sequence[DesignPoint], pad_caps: bool = False,
     path.  Tuning is a per-(node, mem, capacity) argmin over the
     organization axis, so the tuned designs are bit-identical to the
     unpadded ones; only the kernel *shape* changes."""
-    nodes = tuple(dict.fromkeys(p.node for p in points))
-    mems = tuple(dict.fromkeys(p.mem for p in points))
-    caps = tuple(dict.fromkeys(p.capacity_bytes for p in points))
-    lowered = _pad_capacities(caps) if pad_caps else caps
-    table = engine.design_table(mems, lowered, nodes=nodes)
-    designs = tuple(table.tuned(p.mem, p.capacity_bytes, node=p.node)
-                    for p in points)
-    if lowered is not caps:
-        # drop the dummy columns; Algorithm-1 winners carry over
-        table = table.subset(capacities_bytes=caps)
-    return table, designs
+    with tracing.span("lower"):
+        nodes = tuple(dict.fromkeys(p.node for p in points))
+        mems = tuple(dict.fromkeys(p.mem for p in points))
+        caps = tuple(dict.fromkeys(p.capacity_bytes for p in points))
+        lowered = _pad_capacities(caps) if pad_caps else caps
+        table = engine.design_table(mems, lowered, nodes=nodes)
+        designs = tuple(table.tuned(p.mem, p.capacity_bytes, node=p.node)
+                        for p in points)
+        if lowered is not caps:
+            # drop the dummy columns; Algorithm-1 winners carry over
+            table = table.subset(capacities_bytes=caps)
+        return table, designs
 
 
 def _pad_capacities(caps: tuple[int, ...]) -> tuple[int, ...]:
@@ -546,9 +548,10 @@ def _pad_capacities(caps: tuple[int, ...]) -> tuple[int, ...]:
 
 @functools.lru_cache(maxsize=None)
 def _run_cached(spec: SweepSpec) -> SweepResult:
-    table, designs = lower_designs(spec.designs)
-    tables = workload_engine.evaluate_platforms(spec.scenarios, designs,
-                                                spec.platforms)
+    with tracing.span("sweep", spec=spec.name):
+        table, designs = lower_designs(spec.designs)
+        tables = workload_engine.evaluate_platforms(spec.scenarios, designs,
+                                                    spec.platforms)
     return SweepResult(spec=spec, design_table=table, designs=designs,
                        tables=tables)
 
@@ -640,17 +643,20 @@ def _chunk_result(sub: SweepSpec, table: engine.DesignTable,
                   design_of: Mapping[DesignPoint, CacheDesign],
                   tables: tuple[workload_engine.WorkloadTable, ...] | None
                   = None) -> SweepResult:
-    designs = tuple(design_of[p] for p in sub.designs)
-    if tables is None:
-        tables = workload_engine.evaluate_chunk(sub.scenarios, designs,
-                                                sub.platforms)
-    sub_table = table.subset(
-        mems=tuple(dict.fromkeys(p.mem for p in sub.designs)),
-        capacities_bytes=tuple(dict.fromkeys(p.capacity_bytes
-                                             for p in sub.designs)),
-        nodes=tuple(dict.fromkeys(p.node for p in sub.designs)))
-    return SweepResult(spec=sub, design_table=sub_table, designs=designs,
-                       tables=tables)
+    """One chunk's partial result; without ``tables``, its fold runs
+    here."""
+    with tracing.span("assemble", chunk=sub.name):
+        designs = tuple(design_of[p] for p in sub.designs)
+        if tables is None:
+            tables = workload_engine.evaluate_chunk(sub.scenarios, designs,
+                                                    sub.platforms)
+        sub_table = table.subset(
+            mems=tuple(dict.fromkeys(p.mem for p in sub.designs)),
+            capacities_bytes=tuple(dict.fromkeys(p.capacity_bytes
+                                                 for p in sub.designs)),
+            nodes=tuple(dict.fromkeys(p.node for p in sub.designs)))
+        return SweepResult(spec=sub, design_table=sub_table,
+                           designs=designs, tables=tables)
 
 
 def iter_shards(spec: SweepSpec, plan: ShardPlan):
@@ -670,7 +676,9 @@ def iter_shards(spec: SweepSpec, plan: ShardPlan):
     subs = split(spec, plan)
     if plan.devices is None:
         for sub in subs:
-            yield _chunk_result(sub, table, design_of)
+            part = _chunk_result(sub, table, design_of)
+            tracing.count("chunks")
+            yield part
         return
     from repro.distributed.sharding import sweep_mesh
     mesh = sweep_mesh(plan.devices)
@@ -690,9 +698,13 @@ def iter_shards(spec: SweepSpec, plan: ShardPlan):
                 [[design_of[p] for p in b.designs] for b in batch],
                 spec.platforms, mesh)
             for sub, tabs in zip(batch, tables_list):
-                yield _chunk_result(sub, table, design_of, tabs)
+                part = _chunk_result(sub, table, design_of, tabs)
+                tracing.count("chunks")
+                yield part
         for sub in members[full:]:   # ragged tail: plain jit path
-            yield _chunk_result(sub, table, design_of)
+            part = _chunk_result(sub, table, design_of)
+            tracing.count("chunks")
+            yield part
 
 
 def run_sharded(spec: SweepSpec, plan: ShardPlan,
@@ -702,15 +714,16 @@ def run_sharded(spec: SweepSpec, plan: ShardPlan,
     part)`` is called per completed chunk (the CLI's stderr ticker).
     Merged output is pinned to the unsharded path at <= 1e-12 (chunk
     packing may pad reductions differently, so the last ulps can move)."""
-    total = len(split(spec, plan))
+    with tracing.span("sweep", spec=spec.name):
+        total = len(split(spec, plan))
 
-    def parts():
-        for i, part in enumerate(iter_shards(spec, plan)):
-            if progress is not None:
-                progress(i + 1, total, part)
-            yield part
+        def parts():
+            for i, part in enumerate(iter_shards(spec, plan)):
+                if progress is not None:
+                    progress(i + 1, total, part)
+                yield part
 
-    return merge_results(parts(), spec=spec)
+        return merge_results(parts(), spec=spec)
 
 
 # -- merge: order-invariant reassembly of partial results -------------------
@@ -768,57 +781,61 @@ def merge_results(parts: Iterable[SweepResult],
                for f in workload_engine._PLATFORM_DEPENDENT}
     designs: list[CacheDesign | None] = [None] * n_d
     got_any = False
+    # pulling a part runs its chunk (``run_sharded``), so only the
+    # scatter of each part is the merge's own
     for part in parts:
         got_any = True
-        if part.spec.platforms != spec.platforms:
-            raise ValueError(
-                f"chunk {part.spec.name!r} platforms differ from the "
-                "merge target's")
-        if part.spec.baseline_mem != spec.baseline_mem:
-            raise ValueError(
-                f"chunk {part.spec.name!r} baseline_mem differs from the "
-                "merge target's")
-        try:
-            srows = [s_index[k] for k in part.scenario_labels]
-            dcols = [d_index[p] for p in part.spec.designs]
-        except KeyError as e:
-            raise ValueError(f"chunk {part.spec.name!r} carries an axis "
-                             f"label outside the merge target: {e}") \
-                from None
-        block = np.ix_(srows, dcols)
-        if cov[block].any():
-            raise ValueError(
-                f"overlapping chunks: {part.spec.name!r} re-covers "
-                "already-merged (scenario, design) cells")
-        cov[block] = 1
-        for j, d in zip(dcols, part.designs):
-            designs[j] = d
-        t0 = part.tables[0]
-        for f in _SHARED_S:
-            shared_s[f][srows] = getattr(t0, f)
-        for f in _SHARED_SD:
-            shared_sd[f][block] = getattr(t0, f)
-        for pi in range(n_p):
-            for f in workload_engine._PLATFORM_DEPENDENT:
-                platdep[f][pi][block] = getattr(part.tables[pi], f)
+        with tracing.span("merge", chunk=part.spec.name):
+            if part.spec.platforms != spec.platforms:
+                raise ValueError(
+                    f"chunk {part.spec.name!r} platforms differ from the "
+                    "merge target's")
+            if part.spec.baseline_mem != spec.baseline_mem:
+                raise ValueError(
+                    f"chunk {part.spec.name!r} baseline_mem differs from "
+                    "the merge target's")
+            try:
+                srows = [s_index[k] for k in part.scenario_labels]
+                dcols = [d_index[p] for p in part.spec.designs]
+            except KeyError as e:
+                raise ValueError(f"chunk {part.spec.name!r} carries an "
+                                 f"axis label outside the merge target: "
+                                 f"{e}") from None
+            block = np.ix_(srows, dcols)
+            if cov[block].any():
+                raise ValueError(
+                    f"overlapping chunks: {part.spec.name!r} re-covers "
+                    "already-merged (scenario, design) cells")
+            cov[block] = 1
+            for j, d in zip(dcols, part.designs):
+                designs[j] = d
+            t0 = part.tables[0]
+            for f in _SHARED_S:
+                shared_s[f][srows] = getattr(t0, f)
+            for f in _SHARED_SD:
+                shared_sd[f][block] = getattr(t0, f)
+            for pi in range(n_p):
+                for f in workload_engine._PLATFORM_DEPENDENT:
+                    platdep[f][pi][block] = getattr(part.tables[pi], f)
     if not got_any:
         raise ValueError("merge needs at least one partial result")
-    if not cov.all():
-        missing = int((cov == 0).sum())
-        raise ValueError(
-            f"merged chunks do not tile the sweep: {missing} of "
-            f"{n_s * n_d} (scenario, design) cells uncovered")
-    table, _ = lower_designs(spec.designs)
-    keys = tuple(_scenario_key(s) for s in spec.scenarios)
-    tables = tuple(
-        workload_engine.WorkloadTable(
-            scenarios=keys, designs=tuple(designs), platform=p,
-            **shared_s, **shared_sd,
-            **{f: platdep[f][pi]
-               for f in workload_engine._PLATFORM_DEPENDENT})
-        for pi, p in enumerate(spec.platforms))
-    return SweepResult(spec=spec, design_table=table,
-                       designs=tuple(designs), tables=tables)
+    with tracing.span("merge"):
+        if not cov.all():
+            missing = int((cov == 0).sum())
+            raise ValueError(
+                f"merged chunks do not tile the sweep: {missing} of "
+                f"{n_s * n_d} (scenario, design) cells uncovered")
+        table, _ = lower_designs(spec.designs)
+        keys = tuple(_scenario_key(s) for s in spec.scenarios)
+        tables = tuple(
+            workload_engine.WorkloadTable(
+                scenarios=keys, designs=tuple(designs), platform=p,
+                **shared_s, **shared_sd,
+                **{f: platdep[f][pi]
+                   for f in workload_engine._PLATFORM_DEPENDENT})
+            for pi, p in enumerate(spec.platforms))
+        return SweepResult(spec=spec, design_table=table,
+                           designs=tuple(designs), tables=tables)
 
 
 # -- union: superset spec of compatible requests (service coalescing) -------

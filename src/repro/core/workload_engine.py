@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import traffic
 from repro.core.cachemodel import LINE_BYTES, CacheDesign
 from repro.core.tech import Platform, GTX_1080TI
@@ -373,10 +374,25 @@ _PLATFORM_DEPENDENT = ("runtime_s", "runtime_nodram_s", "leak_j",
                        "leak_nodram_j", "dram_j")
 
 
-def _tables_from(out: dict, keys, designs, platforms,
+def _fetch(out: dict, devices: int = 1) -> dict[str, np.ndarray]:
+    """The fold's outputs copied to the host, one blocking copy per device
+    buffer (``devices`` buffers per output).  While spans record, the wait
+    for the device is its own span, so ``fold.fetch`` holds the copies
+    alone."""
+    if tracing.enabled():
+        with tracing.span("fold.wait"):
+            jax.block_until_ready(out)
+    with tracing.span("fold.fetch"):
+        tracing.count("fold.d2h", len(out) * devices)
+        return {k: np.asarray(v) for k, v in out.items()}
+
+
+def _tables_from(out: dict, keys, designs, platforms, on_host: bool = False,
                  ) -> tuple[WorkloadTable, ...]:
-    """One WorkloadTable view per platform from the fold's output dict."""
-    out = {k: np.asarray(v) for k, v in out.items()}
+    """One WorkloadTable view per platform from the fold's output dict
+    (``on_host``: its arrays are already host copies)."""
+    if not on_host:
+        out = _fetch(out)
     shared = {k: v for k, v in out.items() if k not in _PLATFORM_DEPENDENT}
     return tuple(
         WorkloadTable(scenarios=keys, designs=designs, platform=p,
@@ -385,19 +401,32 @@ def _tables_from(out: dict, keys, designs, platforms,
         for i, p in enumerate(platforms))
 
 
+def _fold_args(batch: StreamBatch, designs: Sequence[CacheDesign],
+               platforms: Sequence[Platform]) -> tuple[np.ndarray, ...]:
+    """The fold kernel's 13 host inputs for one packed chunk."""
+    return (batch.bytes_total, batch.is_write, batch.reuse_distance,
+            batch.dram_visible, batch.mask, batch.macs,
+            *_design_vectors(designs),
+            np.stack([_platform_vector(p) for p in platforms]))
+
+
+def _dispatch_fold(args: tuple[np.ndarray, ...]) -> dict:
+    """One call of the jitted fold; each host input lands on one device."""
+    with tracing.span("fold.dispatch"):
+        tracing.count("fold.h2d", len(args))
+        with jax.enable_x64(True):
+            return _fold_kernel(*args)
+
+
 @functools.lru_cache(maxsize=None)
 def _evaluate_cached(stats_seq: tuple[TrafficStats, ...],
                      designs: tuple[CacheDesign, ...],
                      platforms: tuple[Platform, ...],
                      ) -> tuple[WorkloadTable, ...]:
-    batch = pack(stats_seq)
-    rl, wl, re_, we_, leak, caps = _design_vectors(designs)
-    pmat = np.stack([_platform_vector(p) for p in platforms])
-    with jax.enable_x64(True):
-        out = _fold_kernel(batch.bytes_total, batch.is_write,
-                           batch.reuse_distance, batch.dram_visible,
-                           batch.mask, batch.macs,
-                           rl, wl, re_, we_, leak, caps, pmat)
+    with tracing.span("pack"):
+        batch = pack(stats_seq)
+        args = _fold_args(batch, designs, platforms)
+    out = _dispatch_fold(args)
     return _tables_from(out, batch.keys, designs, platforms)
 
 
@@ -438,16 +467,12 @@ def evaluate_chunk(stats_seq: Sequence[TrafficStats],
     outlier-wide scenario inflates only the chunk that contains it."""
     stats_seq = tuple(stats_seq)
     designs = tuple(designs)
-    if width is None:
-        width = pad_width(max(len(s.streams) for s in stats_seq))
-    batch = pack(stats_seq, width=width)
-    rl, wl, re_, we_, leak, caps = _design_vectors(designs)
-    pmat = np.stack([_platform_vector(p) for p in platforms])
-    with jax.enable_x64(True):
-        out = _fold_kernel(batch.bytes_total, batch.is_write,
-                           batch.reuse_distance, batch.dram_visible,
-                           batch.mask, batch.macs,
-                           rl, wl, re_, we_, leak, caps, pmat)
+    with tracing.span("pack"):
+        if width is None:
+            width = pad_width(max(len(s.streams) for s in stats_seq))
+        batch = pack(stats_seq, width=width)
+        args = _fold_args(batch, designs, platforms)
+    out = _dispatch_fold(args)
     return _tables_from(out, batch.keys, designs, tuple(platforms))
 
 
@@ -493,22 +518,28 @@ def evaluate_chunk_group(chunk_stats: Sequence[Sequence[TrafficStats]],
             len({len(cd) for cd in chunk_designs}) != 1:
         raise ValueError("chunks in a sharded group must share scenario "
                          "and design counts")
-    width = pad_width(max(len(s.streams)
-                          for cs in chunk_stats for s in cs))
-    batches = [pack(tuple(cs), width=width) for cs in chunk_stats]
-    stacked = [np.stack([getattr(b, f) for b in batches])
-               for f in ("bytes_total", "is_write", "reuse_distance",
-                         "dram_visible", "mask", "macs")]
-    vecs = [np.stack(v) for v in
-            zip(*(_design_vectors(tuple(cd)) for cd in chunk_designs))]
-    pmat = np.stack([_platform_vector(p) for p in platforms])
-    with jax.enable_x64(True):
-        out = _sharded_fold(mesh)(*stacked, *vecs, pmat)
-    out = {k: np.asarray(v) for k, v in out.items()}
-    return [_tables_from({k: v[i] for k, v in out.items()},
-                         batches[i].keys, tuple(chunk_designs[i]),
-                         tuple(platforms))
-            for i in range(g)]
+    with tracing.span("pack", chunks=g):
+        width = pad_width(max(len(s.streams)
+                              for cs in chunk_stats for s in cs))
+        batches = [pack(tuple(cs), width=width) for cs in chunk_stats]
+        stacked = [np.stack([getattr(b, f) for b in batches])
+                   for f in ("bytes_total", "is_write", "reuse_distance",
+                             "dram_visible", "mask", "macs")]
+        vecs = [np.stack(v) for v in
+                zip(*(_design_vectors(tuple(cd)) for cd in chunk_designs))]
+        pmat = np.stack([_platform_vector(p) for p in platforms])
+    with tracing.span("fold.dispatch", chunks=g):
+        # each chunk-axis input splits one slice onto every device of the
+        # mesh; the platform matrix is replicated onto each
+        tracing.count("fold.h2d", (len(stacked) + len(vecs) + 1) * g)
+        with jax.enable_x64(True):
+            out = _sharded_fold(mesh)(*stacked, *vecs, pmat)
+    out = _fetch(out, devices=g)
+    with tracing.span("assemble", chunks=g):
+        return [_tables_from({k: v[i] for k, v in out.items()},
+                             batches[i].keys, tuple(chunk_designs[i]),
+                             tuple(platforms), on_host=True)
+                for i in range(g)]
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,10 @@ spec instead takes the chunked/sharded lowering (``core.sweep.ShardPlan``)
 and streams partial results through the order-invariant merge — the path
 for mega-specs too large for one fold.  ``mega`` builds and runs the full
 DTCO cross product (``repro.scenarios.mega_spec``, 1e5+ cells) through
-that path.  ``show`` resolves without evaluating (spec linting).
+that path.  ``show`` resolves without evaluating (spec linting).  ``run`` and
+``mega`` take ``--profile DIR``: the evaluation runs under a JAX profiler
+trace written to DIR, and the program's spans (:mod:`repro.tracing`) are
+printed to stderr as self time per span.
 
 ``invert`` runs the gradient-based inverse-design solver
 (:mod:`repro.inverse`) over a spec's corner grid: it accepts either a
@@ -107,6 +110,13 @@ def _progress(i: int, total: int, part) -> None:
           end="" if i < total else "\n", file=sys.stderr, flush=True)
 
 
+def _add_profile_flag(p: argparse.ArgumentParser) -> None:
+    p.add_argument("--profile", metavar="DIR",
+                   help="run the evaluation under a JAX profiler trace "
+                        "written to DIR, and print the time spent in each "
+                        "span of the sweep pipeline to stderr")
+
+
 def _add_shard_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--shard", type=int, metavar="N",
                    help="sharded lowering: chunk the scenario axis by N")
@@ -120,16 +130,37 @@ def _add_shard_flags(p: argparse.ArgumentParser) -> None:
                         "(minimizes padded-SoA area per chunk)")
 
 
-def _run_spec(spec, plan: ShardPlan | None):
+def _run_spec(spec, plan: ShardPlan | None, profile: str | None = None):
+    """Evaluate ``spec``; with ``profile``, under a JAX profiler trace
+    written to that directory, then print the program's spans (self time
+    per span, counters) to stderr."""
     from repro.core import sweep as sweep_mod
-    if plan is None:
-        return sweep_mod.run(spec)
-    return sweep_mod.run_sharded(spec, plan, progress=_progress)
+
+    def evaluate():
+        if plan is None:
+            return sweep_mod.run(spec)
+        return sweep_mod.run_sharded(spec, plan, progress=_progress)
+
+    if profile is None:
+        return evaluate()
+    import jax
+
+    from repro import tracing
+    opts = jax.profiler.ProfileOptions()
+    # the program's own spans stand in for the Python tracer, which would
+    # slow every call it times several fold
+    opts.python_tracer_level = 0
+    tracing.reset()
+    with jax.profiler.trace(profile, profiler_options=opts):
+        result = evaluate()
+    print(f"trace -> {profile}", file=sys.stderr)
+    print(tracing.table(tracing.summary()), file=sys.stderr)
+    return result
 
 
 def cmd_run(args: argparse.Namespace) -> None:
     sym = _load(args.spec)
-    result = _run_spec(sym.resolve(), _plan_of(args))
+    result = _run_spec(sym.resolve(), _plan_of(args), args.profile)
     rows = result.rows(include_norm=not args.no_norm,
                        include_dram=args.include_dram)
     # status lines go to stderr: stdout carries only data (the rows CSV
@@ -172,7 +203,7 @@ def cmd_mega(args: argparse.Namespace) -> None:
           f"scenarios x {len(spec.designs)} designs), plan {plan}",
           file=sys.stderr)
     t0 = time.perf_counter()
-    result = _run_spec(spec, plan)
+    result = _run_spec(spec, plan, args.profile)
     dt = time.perf_counter() - t0
     print(f"evaluated in {dt:.1f}s "
           f"({cells_of(spec) / dt:,.0f} cells/s)", file=sys.stderr)
@@ -362,6 +393,7 @@ def main(argv: list[str] | None = None, *,
     run_p.add_argument("--include-dram", action="store_true",
                        help="include DRAM terms in energy/EDP columns")
     _add_shard_flags(run_p)
+    _add_profile_flag(run_p)
     run_p.set_defaults(func=cmd_run)
 
     mega_p = sub.add_parser(
@@ -373,6 +405,7 @@ def main(argv: list[str] | None = None, *,
     mega_p.add_argument("--summary", action="store_true",
                         help="print the aggregate summary as JSON")
     _add_shard_flags(mega_p)
+    _add_profile_flag(mega_p)
     mega_p.set_defaults(func=cmd_mega)
 
     inv_p = sub.add_parser(
