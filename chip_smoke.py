@@ -451,7 +451,7 @@ def phase_four_chips(clock: CompileClock, kind: str) -> None:
     check(len(ids) == 4, f"sweep mesh spans devices {sorted(ids)}")
     say(f"  sweep mesh: {mesh.devices.size} devices, ids {sorted(ids)}")
 
-    # record where each shard_map'd group's outputs live
+    # record where each shard_map'd group's packed output lives
     placed: list[set[int]] = []
     fold_for = workload_engine._sharded_fold
 
@@ -460,8 +460,7 @@ def phase_four_chips(clock: CompileClock, kind: str) -> None:
 
         def run(*args):
             out = fold(*args)
-            placed.append({s.device.id for v in out.values()
-                           for s in v.addressable_shards})
+            placed.append({s.device.id for s in out.addressable_shards})
             return out
         return run
 

@@ -249,7 +249,47 @@ def _fold(bytes_total, is_write, rd, visible, mask, macs,
     )
 
 
-_fold_kernel = jax.jit(_fold)
+# The fold's ten outputs in the order the packed kernel lays them out, each
+# with its axes over (platform, scenario, design).
+_FOLD_LAYOUT = (("l2_read_tx", "s"), ("l2_write_tx", "s"), ("dram_tx", "sd"),
+                ("runtime_s", "psd"), ("runtime_nodram_s", "psd"),
+                ("dyn_read_j", "sd"), ("dyn_write_j", "sd"),
+                ("leak_j", "psd"), ("leak_nodram_j", "psd"),
+                ("dram_j", "psd"))
+
+
+def _pack_outputs(out: dict) -> jax.Array:
+    """The fold's output dict raveled into one 1-D buffer, in
+    ``_FOLD_LAYOUT`` order."""
+    return jnp.concatenate([out[k].ravel() for k, _ in _FOLD_LAYOUT])
+
+
+@jax.jit
+@functools.wraps(_fold)
+def _fold_packed(*args):
+    """``_fold`` returning one packed buffer, so the host copies a chunk's
+    answer out in one transfer instead of ten.  ``_fold`` is looked up
+    when traced, not captured here.  The profiler must keep naming the
+    program ``_fold`` (hence ``wraps``), so device profiles of the fold
+    stay comparable from one version to the next."""
+    return _pack_outputs(_fold(*args))
+
+
+def _unpack(buf: np.ndarray, p: int, s: int, d: int,
+            ) -> dict[str, np.ndarray]:
+    """The fold's outputs by name as views of one packed host buffer; the
+    layout follows from the (platform, scenario, design) sizes alone."""
+    size = {"p": p, "s": s, "d": d}
+    out, at = {}, 0
+    for name, axes in _FOLD_LAYOUT:
+        shape = tuple(size[a] for a in axes)
+        n = int(np.prod(shape))
+        out[name] = buf[at:at + n].reshape(shape)
+        at += n
+    if at != buf.size:
+        raise ValueError(f"packed fold buffer of {buf.size} values is not "
+                         f"the ({p}, {s}, {d}) layout's {at}")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -370,29 +410,34 @@ class WorkloadTable:
 
 # Result-tensor names that carry a leading platform axis in the kernel
 # output; the rest are platform-independent and shared across the views.
-_PLATFORM_DEPENDENT = ("runtime_s", "runtime_nodram_s", "leak_j",
-                       "leak_nodram_j", "dram_j")
+_PLATFORM_DEPENDENT = tuple(k for k, axes in _FOLD_LAYOUT if "p" in axes)
 
 
-def _fetch(out: dict, devices: int = 1) -> dict[str, np.ndarray]:
-    """The fold's outputs copied to the host, one blocking copy per device
-    buffer (``devices`` buffers per output).  While spans record, the wait
-    for the device is its own span, so ``fold.fetch`` holds the copies
+def _fetch(out: jax.Array, devices: int = 1) -> np.ndarray:
+    """The packed fold output copied to the host, one blocking copy per
+    device buffer (``devices`` of them).  While spans record, the wait for
+    the device is its own span, so ``fold.fetch`` holds the copies
     alone."""
     if tracing.enabled():
         with tracing.span("fold.wait"):
             jax.block_until_ready(out)
     with tracing.span("fold.fetch"):
-        tracing.count("fold.d2h", len(out) * devices)
-        return {k: np.asarray(v) for k, v in out.items()}
+        tracing.count("fold.d2h", devices)
+        return np.asarray(out)
 
 
-def _tables_from(out: dict, keys, designs, platforms, on_host: bool = False,
+def _fetch_tables(out: jax.Array, keys, designs, platforms,
+                  ) -> tuple[WorkloadTable, ...]:
+    """The plain fold's packed output, copied out once and viewed as one
+    WorkloadTable per platform."""
+    host = _unpack(_fetch(out), len(platforms), len(keys), len(designs))
+    return _tables_from(host, keys, designs, platforms)
+
+
+def _tables_from(out: dict, keys, designs, platforms,
                  ) -> tuple[WorkloadTable, ...]:
-    """One WorkloadTable view per platform from the fold's output dict
-    (``on_host``: its arrays are already host copies)."""
-    if not on_host:
-        out = _fetch(out)
+    """One WorkloadTable view per platform from the fold's outputs by name,
+    already on the host."""
     shared = {k: v for k, v in out.items() if k not in _PLATFORM_DEPENDENT}
     return tuple(
         WorkloadTable(scenarios=keys, designs=designs, platform=p,
@@ -410,12 +455,12 @@ def _fold_args(batch: StreamBatch, designs: Sequence[CacheDesign],
             np.stack([_platform_vector(p) for p in platforms]))
 
 
-def _dispatch_fold(args: tuple[np.ndarray, ...]) -> dict:
-    """One call of the jitted fold; each host input lands on one device."""
+def _dispatch_fold(args: tuple[np.ndarray, ...]) -> jax.Array:
+    """One call of the packed fold; each host input lands on one device."""
     with tracing.span("fold.dispatch"):
         tracing.count("fold.h2d", len(args))
         with jax.enable_x64(True):
-            return _fold_kernel(*args)
+            return _fold_packed(*args)
 
 
 @functools.lru_cache(maxsize=None)
@@ -426,8 +471,8 @@ def _evaluate_cached(stats_seq: tuple[TrafficStats, ...],
     with tracing.span("pack"):
         batch = pack(stats_seq)
         args = _fold_args(batch, designs, platforms)
-    out = _dispatch_fold(args)
-    return _tables_from(out, batch.keys, designs, platforms)
+    return _fetch_tables(_dispatch_fold(args), batch.keys, designs,
+                         platforms)
 
 
 def evaluate(stats_seq: Sequence[TrafficStats],
@@ -472,8 +517,8 @@ def evaluate_chunk(stats_seq: Sequence[TrafficStats],
             width = pad_width(max(len(s.streams) for s in stats_seq))
         batch = pack(stats_seq, width=width)
         args = _fold_args(batch, designs, platforms)
-    out = _dispatch_fold(args)
-    return _tables_from(out, batch.keys, designs, tuple(platforms))
+    return _fetch_tables(_dispatch_fold(args), batch.keys, designs,
+                         tuple(platforms))
 
 
 @functools.lru_cache(maxsize=None)
@@ -481,7 +526,9 @@ def _sharded_fold(mesh):
     """The fold, shard_mapped over a 1-D sweep mesh: every input carries a
     leading chunk axis split across devices (the platform matrix is
     replicated), and each device evaluates its chunk independently — the
-    fold has no cross-chunk terms, so no collectives are needed."""
+    fold has no cross-chunk terms, so no collectives are needed.  Each
+    chunk's outputs come back packed, one row of a [chunk, N] buffer, so
+    a group is one device buffer per device."""
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import SWEEP_AXIS
@@ -492,7 +539,7 @@ def _sharded_fold(mesh):
              pmat):
         out = _fold(bt[0], iw[0], rd[0], vis[0], mask[0], macs[0],
                     rl[0], wl[0], re_[0], we_[0], leak[0], caps[0], pmat)
-        return {k: v[None] for k, v in out.items()}
+        return _pack_outputs(out)[None]
 
     return jax.jit(jax.shard_map(body, mesh=mesh,
                                  in_specs=(sh,) * 12 + (P(),),
@@ -535,10 +582,10 @@ def evaluate_chunk_group(chunk_stats: Sequence[Sequence[TrafficStats]],
         with jax.enable_x64(True):
             out = _sharded_fold(mesh)(*stacked, *vecs, pmat)
     out = _fetch(out, devices=g)
+    p, s, d = len(platforms), len(chunk_stats[0]), len(chunk_designs[0])
     with tracing.span("assemble", chunks=g):
-        return [_tables_from({k: v[i] for k, v in out.items()},
-                             batches[i].keys, tuple(chunk_designs[i]),
-                             tuple(platforms), on_host=True)
+        return [_tables_from(_unpack(out[i], p, s, d), batches[i].keys,
+                             tuple(chunk_designs[i]), tuple(platforms))
                 for i in range(g)]
 
 
@@ -594,16 +641,11 @@ def evaluate_bucketed(stats_seq: Sequence[TrafficStats],
     vecs = [np.pad(v, (0, dp - d)) for v in _design_vectors(designs)]
     pmat = np.stack([_platform_vector(p) for p in platforms])
     with jax.enable_x64(True):
-        out = _fold_kernel(bt, iw, rd, vis, mask, macs, *vecs, pmat)
-    sliced = {}
-    for k, v in out.items():
-        v = np.asarray(v)
-        if v.ndim == 1:                 # [s] platform-independent
-            sliced[k] = v[:s]
-        elif v.ndim == 2:               # [s, d] platform-independent
-            sliced[k] = v[:s, :d]
-        else:                           # [p, s, d]
-            sliced[k] = v[:, :s, :d]
+        out = _fold_packed(bt, iw, rd, vis, mask, macs, *vecs, pmat)
+    padded = _unpack(np.asarray(out), len(platforms), sp, dp)
+    real = {"p": slice(None), "s": slice(s), "d": slice(d)}
+    sliced = {k: padded[k][tuple(real[a] for a in axes)]
+              for k, axes in _FOLD_LAYOUT}
     return _tables_from(sliced, batch.keys, designs, platforms)
 
 
@@ -627,7 +669,7 @@ def warmup_fold(shape: tuple[int, int, int, int]) -> None:
     vec = np.zeros(d)
     pmat = np.ones((p, len(PLATFORM_FIELDS)))  # ones: no 0-divides
     with jax.enable_x64(True):
-        _fold_kernel(zeros_sk, false_sk, np.full((s, k), np.inf), false_sk,
+        _fold_packed(zeros_sk, false_sk, np.full((s, k), np.inf), false_sk,
                      false_sk, np.zeros(s), vec, vec, vec, vec, vec,
                      np.ones(d), pmat)
 

@@ -7,11 +7,13 @@ slices, programs that do not fit).  Cases:
 
   ppa        ``engine._ppa_kernel``, both ``anchor_peri`` traces, at the
              mega-sweep's 4 nodes x 3 mems x 24 capacities x 288 orgs;
-  fold       ``workload_engine._fold_kernel`` at the mega-sweep chunk
-             shape and at the golden isocap spec's bucketed shape;
+  fold       ``workload_engine._fold`` jitted as it stands, and the packed
+             ``_fold_packed`` that the sweeps and the service call, each at
+             the mega-sweep chunk shape and at the golden isocap spec's
+             bucketed shape;
   sharded    the ``shard_map``'d fold on a 4-chip sweep mesh, which must
              hold no cross-chip collective (the fold has no cross-chunk
-             terms);
+             terms) and returns each chunk's packed buffer as one row;
   pallas     the flash-attention and WKV6 kernels at the shapes
              ``kernels/ops.py`` hands them on the TPU.
 
@@ -38,6 +40,10 @@ from repro.core import engine, workload_engine
 from repro.core.sweep import SymbolicSweepSpec
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+# the mega-sweep's fold chunk (s, k, d, p) and its packed output's length:
+# 2 [s] + 3 [s, d] + 5 [p, s, d] float64 values
+MEGA_CHUNK = (8, 1024, 32, 2)
+PACKED_N = 3344
 
 
 @pytest.fixture(scope="module")
@@ -117,17 +123,34 @@ def test_ppa_kernel_compiles(one_chip, chip_compile, anchor_peri):
     assert out["leakage_w"].shape == (n, m, c)
 
 
-@pytest.mark.parametrize("shape", [
-    pytest.param((8, 1024, 32, 2), id="mega-chunk"),
+FOLD_SHAPES = [
+    pytest.param(MEGA_CHUNK, id="mega-chunk"),
     pytest.param("isocap", id="golden-isocap"),
-])
+]
+
+
+def _fold_shape(shape) -> tuple[int, int, int, int]:
+    return _golden_fold_shape(shape) if isinstance(shape, str) else shape
+
+
+@pytest.mark.parametrize("shape", FOLD_SHAPES)
 def test_fold_kernel_compiles(one_chip, chip_compile, shape):
-    s, k, d, p = _golden_fold_shape(shape) if isinstance(shape, str) \
-        else shape
-    compiled = workload_engine._fold_kernel.lower(
+    s, k, d, p = _fold_shape(shape)
+    compiled = jax.jit(workload_engine._fold).lower(
         *_fold_args(one_chip, s, k, d, p)).compile()
     assert compiled.out_info["runtime_s"].shape == (p, s, d)
     assert compiled.out_info["dram_tx"].dtype == jnp.float64
+
+
+@pytest.mark.parametrize("shape", FOLD_SHAPES)
+def test_packed_fold_kernel_compiles(one_chip, chip_compile, shape):
+    s, k, d, p = _fold_shape(shape)
+    compiled = workload_engine._fold_packed.lower(
+        *_fold_args(one_chip, s, k, d, p)).compile()
+    assert compiled.out_info.shape == (2 * s + 3 * s * d + 5 * p * s * d,)
+    assert compiled.out_info.dtype == jnp.float64
+    if shape == MEGA_CHUNK:
+        assert compiled.out_info.shape == (PACKED_N,)
 
 
 def test_sharded_fold_has_no_collectives(topo, chip_compile):
@@ -136,10 +159,10 @@ def test_sharded_fold_has_no_collectives(topo, chip_compile):
     from repro.distributed.sharding import SWEEP_AXIS
 
     mesh = Mesh(np.array(topo.devices[:4]), (SWEEP_AXIS,))
-    args = _fold_args(NamedSharding(mesh, P(SWEEP_AXIS)), 8, 1024, 32, 2,
+    args = _fold_args(NamedSharding(mesh, P(SWEEP_AXIS)), *MEGA_CHUNK,
                       lead=(4,), pmat_sharding=NamedSharding(mesh, P()))
     compiled = workload_engine._sharded_fold(mesh).lower(*args).compile()
-    assert compiled.out_info["runtime_s"].shape == (4, 2, 8, 32)
+    assert compiled.out_info.shape == (4, PACKED_N)
     hlo = compiled.as_text()
     for collective in ("all-reduce", "all-gather", "all-to-all",
                        "collective-permute", "reduce-scatter"):

@@ -22,7 +22,7 @@ PLAN = sweep.ShardPlan(scenario_chunk=8, design_chunk=32, by_width=True)
 SPANS = ("sweep", "lower", "ppa.dispatch", "ppa.fetch", "tune", "pack",
          "fold.dispatch", "fold.wait", "fold.fetch", "assemble", "merge")
 PER_CHUNK = ("pack", "fold.dispatch", "fold.fetch", "assemble")
-FOLD_INPUTS, FOLD_OUTPUTS = 13, 10
+FOLD_INPUTS = 13
 
 
 def clear_memos():
@@ -121,7 +121,8 @@ def test_transfer_counters_per_chunk(spec, tmp_path, devices):
     chunks = s["counters"]["chunks"]
     assert chunks == len(sweep.split(spec, plan))
     assert s["counters"]["fold.h2d"] == FOLD_INPUTS * chunks
-    assert s["counters"]["fold.d2h"] == FOLD_OUTPUTS * chunks
+    # the fold's ten outputs come back packed: one copy per device buffer
+    assert s["counters"]["fold.d2h"] == chunks
     # one copy-out per chunk on either path, never one more
     assert s["spans"]["fold.fetch"]["calls"] == chunks
 
