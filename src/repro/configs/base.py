@@ -20,7 +20,7 @@ class MoESpec:
 
 @dataclasses.dataclass(frozen=True)
 class MLASpec:
-    q_lora_rank: int = 1536
+    q_lora_rank: int = 1536       # 0: no q LoRA, q projected at full rank
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -34,6 +34,23 @@ class SSMSpec:
     # hybrid (Hymba): indices of global-attention layers; others use SWA
     global_attn_layers: tuple[int, ...] = ()
     sliding_window: int = 1024
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearAttnSpec:
+    """A hybrid stack of linear-attention and full-attention layers (Kimi
+    Linear style).  Layer numbers are 1-based, as the published config
+    lists them; the full layers take the config's MLA.  Each linear layer
+    is Kimi Delta Attention: a gated delta-rule recurrence with an fp32
+    ``head_dim x head_dim`` state per head, a depthwise short convolution
+    of ``conv_kernel`` taps on q, k and v, and low-rank (``gate_rank``)
+    projections for the channel-wise decay and the output gate."""
+    kda_layers: tuple[int, ...]
+    full_attn_layers: tuple[int, ...]
+    n_heads: int
+    head_dim: int
+    conv_kernel: int = 4
+    gate_rank: int = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +83,7 @@ class ArchConfig:
     ssm: Optional[SSMSpec] = None
     rwkv: bool = False
     encdec: Optional[EncDecSpec] = None
+    linear_attn: Optional[LinearAttnSpec] = None
 
     @property
     def sub_quadratic(self) -> bool:

@@ -378,17 +378,20 @@ class SymbolicSweepSpec:
     def resolve(self) -> SweepSpec:
         """Lower to a concrete spec (today's axes): scenario names through
         the unified registry, design names/grids to DesignPoints, platform
-        names through ``tech.PLATFORMS``."""
+        names through ``tech.PLATFORMS``.  Traced, it is a ``resolve``
+        span whose ``scenarios`` counter counts the names resolved."""
         # repro.scenarios builds on this module; resolve late to keep the
         # registry layering acyclic.
         from repro import scenarios as scenario_registry
-        return SweepSpec(
-            name=self.name,
-            scenarios=tuple(scenario_registry.resolve(n)
-                            for n in self.scenarios),
-            designs=self.design_points(),
-            platforms=tuple(tech.platform(p) for p in self.platforms),
-            baseline_mem=self.baseline_mem)
+        with tracing.span("resolve", spec=self.name):
+            tracing.count("scenarios", len(self.scenarios))
+            return SweepSpec(
+                name=self.name,
+                scenarios=tuple(scenario_registry.resolve(n)
+                                for n in self.scenarios),
+                designs=self.design_points(),
+                platforms=tuple(tech.platform(p) for p in self.platforms),
+                baseline_mem=self.baseline_mem)
 
     def run(self, plan: ShardPlan | None = None) -> SweepResult:
         return self.resolve().run(plan)

@@ -10,11 +10,13 @@ accounting the roofline uses (``launch/flops.py``), so the whole LM study
 runs as one batched [arch-shape] x [mem, capacity] x [platform] fold on
 the workload engine.
 
-Both scenario kinds live under one namespace, resolved by :func:`resolve`
+All scenario kinds live under one namespace, resolved by :func:`resolve`
 (the symbolic SweepSpec v2 scenario axis, core/sweep.py):
 
     cnn/<workload>/<stage>@b<batch>   e.g. "cnn/resnet18/train@b64"
     lm/<arch>/<shape>[@b<batch>]      e.g. "lm/qwen3-14b/decode_32k@b8"
+    serve/<arch>/decode@c<context>@b<batch>@p<prefix %>
+                      e.g. "serve/kimi-linear-48b-a3b/decode@c4096@b8@p75"
 
 The LM ``@b<n>`` suffix overrides the shape's default global batch
 (``configs.base.SHAPES``), so serving-fleet batch mixes sweep as
@@ -24,6 +26,11 @@ batch (the historical LM-study rows are unchanged).
 ``name_of`` is the inverse (used to serialize concrete specs), and a
 heterogeneous spec may mix both kinds on one scenario axis — they fold in
 a single batched evaluation.
+
+``serve/`` is one decode step of a served batch with finite reuse
+distances, a batch-dependent count of routed experts touched and a prefix
+shared across the batch (``core/lm_serve.py``), for the architectures in
+``SERVE_ARCHS``; its cells are built by :func:`serve_traffic`.
 
 ``long_500k`` (524k-token decode) is only meaningful for sub-quadratic
 architectures (SSM / hybrid / linear attention); ``lm_supported`` encodes
@@ -35,11 +42,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from collections.abc import Sequence
 
 import repro.configs as configs
 from repro.configs.base import SHAPES
-from repro.core import sweep, workload_engine, workloads
+from repro import tracing
+from repro.core import lm_serve, sweep, workload_engine, workloads
 from repro.core.tech import Platform, TechNode, TECH_16NM, TPU_V5E
 from repro.core.traffic import INF, AccessStream, TrafficStats
 from repro.launch import flops as flops_mod
@@ -97,6 +106,44 @@ def lm_traffic(arch: str, shape_name: str,
     return TrafficStats(key, shape.global_batch,
                         shape.kind == "train", tuple(streams),
                         macs_per_batch=acct.flops / 2.0)
+
+
+# Architectures of the serve/ namespace: hybrid KDA + MLA + MoE stacks,
+# which the lm/ accounting (flops.account) does not model.
+SERVE_ARCHS = ("kimi-linear-48b-a3b",)
+
+
+@functools.lru_cache(maxsize=None)
+def serve_traffic(arch: str, context: int, batch: int,
+                  prefix_pct: int) -> TrafficStats:
+    """One decode step of ``batch`` sequences of ``context`` tokens, of
+    which ``prefix_pct`` percent are a prefix the batch shares
+    (``lm_serve.decode_step``).  Memoized like :func:`lm_traffic`; each
+    build is a ``lm.streams`` span and adds its streams to the ``streams``
+    counter."""
+    key = f"serve/{arch}/decode@c{context}@b{batch}@p{prefix_pct}"
+    with tracing.span("lm.streams", scenario=key):
+        stats = lm_serve.decode_step(configs.get(arch), context, batch,
+                                     prefix_pct, key)
+        tracing.count("streams", len(stats.streams))
+    return stats
+
+
+_SERVE_NAME = re.compile(
+    r"serve/([^/]+)/decode@c([1-9]\d*)@b([1-9]\d*)@p(0|[1-9]\d*)")
+
+
+def _serve_resolve(name: str) -> TrafficStats:
+    m = _SERVE_NAME.fullmatch(name)
+    if m is None:
+        raise ValueError(f"bad serve scenario {name!r}: expected "
+                         "'serve/<arch>/decode@c<context>@b<batch>"
+                         "@p<prefix %>' (integers, no leading zeros)")
+    arch, context, batch, prefix_pct = m.groups()
+    if arch not in SERVE_ARCHS:
+        raise ValueError(f"bad serve scenario {name!r}: unknown arch "
+                         f"{arch!r}; available: {SERVE_ARCHS}")
+    return serve_traffic(arch, int(context), int(batch), int(prefix_pct))
 
 
 def lm_supported(arch: str, shape_name: str) -> bool:
@@ -158,13 +205,18 @@ def resolve(name: str) -> TrafficStats:
             raise ValueError(f"unsupported LM scenario {name!r}: "
                              f"{shape} needs a sub-quadratic architecture")
         return lm_traffic(arch, shape, int(batch_s) if sep else None)
+    if kind == "serve":
+        return _serve_resolve(name)
     raise ValueError(f"unknown scenario namespace in {name!r}: expected "
-                     "'cnn/...' or 'lm/...'")
+                     "'cnn/...', 'lm/...' or 'serve/...'")
 
 
 def name_of(stats: TrafficStats) -> str:
     """Inverse of :func:`resolve` — the symbolic name of a registry-built
-    scenario (LM cells carry their 'arch/shape' key as the workload)."""
+    scenario (LM cells carry their 'arch/shape' key as the workload, serve
+    cells their whole name)."""
+    if stats.workload.startswith("serve/"):
+        return stats.workload
     if "/" in stats.workload:
         return f"lm/{stats.workload}"
     stage = "train" if stats.training else "infer"

@@ -208,3 +208,58 @@ def test_cli_mega_quick_profile(tmp_path, capsys):
     assert glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
                      recursive=True)
     assert not tracing.enabled()
+
+
+# ---------------------------------------------------------------------------
+# Resolving a symbolic spec: the `resolve` root, `lm.streams` per new
+# serve/ scenario, counters `scenarios` and `streams`
+# ---------------------------------------------------------------------------
+
+SERVE_SPEC = os.path.join(os.path.dirname(__file__), "..", "specs",
+                          "kimi_linear_serve.json")
+
+
+@pytest.fixture(scope="module")
+def serve_sym():
+    return sweep.SymbolicSweepSpec.load(SERVE_SPEC)
+
+
+def test_resolve_is_a_root_with_one_lm_streams_span_per_new_scenario(
+        serve_sym, tmp_path):
+    scenarios.serve_traffic.cache_clear()
+    spec, s, recs = traced(tmp_path, serve_sym.resolve)
+    n = len(serve_sym.scenarios)
+    assert tracing.summary()["roots"] == 0     # no sweep ran
+    r = tracing.summary(root="resolve")
+    assert r["roots"] == 1 and set(r["spans"]) == {"resolve", "lm.streams"}
+    assert r["spans"]["lm.streams"]["calls"] == n
+    assert r["counters"] == {
+        "scenarios": n, "streams": sum(len(x.streams) for x in spec.scenarios)}
+    root, = [x for x in recs if x.parent_id is None]
+    assert root.name == "resolve" and not root.error
+    assert {x.parent_id for x in recs if x.name == "lm.streams"} == {root.id}
+    assert {x.attrs["scenario"] for x in recs if x.name == "lm.streams"} \
+        == set(serve_sym.scenarios)
+    assert sum(x.self_ns for x in recs) == root.duration_ns
+
+
+def test_a_memo_hit_builds_no_streams(serve_sym, tmp_path):
+    scenarios.serve_traffic.cache_clear()
+    serve_sym.resolve()                        # untraced: fills the memo
+
+    def twice():
+        return serve_sym.resolve(), serve_sym.resolve()
+
+    (a, b), _, _ = traced(tmp_path, twice)
+    assert a == b
+    r = tracing.summary(root="resolve")
+    assert r["roots"] == 2 and set(r["spans"]) == {"resolve"}
+    assert r["counters"] == {"scenarios": 2 * len(serve_sym.scenarios)}
+
+
+def test_resolve_records_nothing_untraced(serve_sym):
+    scenarios.serve_traffic.cache_clear()
+    tracing.reset()
+    serve_sym.resolve()
+    assert tracing.records() == []
+    assert tracing.summary(root="resolve")["counters"] == {}
